@@ -55,11 +55,11 @@ class HiStoreConfig:
                                    # ran for this long
     # telemetry ------------------------------------------------------------
     telemetry: str = "counters"    # "off": record nothing (snapshots never
-                                   # change); "counters": op counters +
-                                   # log-bucketed latency histograms (the
-                                   # default — no device syncs added);
-                                   # "trace": counters + a bounded ring of
-                                   # per-op spans for forensics
+                                   # change); "counters" (the default): op
+                                   # counters + log-bucketed latency
+                                   # histograms, no device access, and
+                                   # histore.* profiler spans that record
+                                   # only under a profiler session
                                    # (core/telemetry.py)
     # batching -------------------------------------------------------------
     async_apply_batch: int = 4096  # log entries merged into the sorted index

@@ -151,7 +151,7 @@ class LocalBackend:
             self.drain()
 
     def put(self, keys, vals, valid):
-        n = int(valid.sum())
+        n = int(self.telemetry.sync(valid.sum()))
         self._ensure_log_room(n)
         self._pending_bound += n
         ba = tuple(self._backups_alive)
@@ -167,7 +167,7 @@ class LocalBackend:
                           hint)
 
     def delete(self, keys, valid):
-        n = int(valid.sum())
+        n = int(self.telemetry.sync(valid.sum()))
         self._ensure_log_room(n)
         self._pending_bound += n
         ba = tuple(self._backups_alive)
@@ -186,16 +186,21 @@ class LocalBackend:
         return k, a, n, jnp.ones((1,), bool)
 
     def apply_async(self):
+        self.telemetry.count("apply_rounds")
         self.group = ig.apply_async(self.group, self.cfg)
         self._pending_bound = max(
             0, self._pending_bound - self.cfg.async_apply_batch)
 
     def drain(self):
-        self.group = ig.drain(self.group, self.cfg)
-        self._pending_bound = 0
+        tel = self.telemetry
+        with tel.span("drain"):
+            tel.count("drains")
+            self.group = ig.drain(self.group, self.cfg)
+            self._pending_bound = 0
 
     def pending_ops(self) -> int:
-        return int(lg.pending_count(self.group.blogs).max())
+        return int(self.telemetry.sync(
+            lg.pending_count(self.group.blogs).max()))
 
     def telemetry_gauges(self) -> dict:
         """Snapshot-time gauges: the single node's liveness is host-side
@@ -242,8 +247,6 @@ class LocalBackend:
         else:
             self._backups_alive[server - 1] = False
         self.telemetry.count("index_demotions")
-        self.telemetry.span({"event": "demote", "plane": "index",
-                             "server": server, "detected": False})
 
     def recover_server(self, server: int = 0, online: bool = True):
         if server == 0:
@@ -255,8 +258,6 @@ class LocalBackend:
                                            self.cfg, online=online)
             self._backups_alive[server - 1] = True
         self.telemetry.count("index_recoveries")
-        self.telemetry.span({"event": "recover", "plane": "index",
-                             "server": server, "online": online})
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +292,8 @@ def _lease_ticker_loop(ref, stop: threading.Event) -> None:
                 # just run (its _lease_tick refreshed the timestamp)
                 if time.monotonic() - be._last_traffic_t < interval:
                     continue
-                be._lease_tick(bump=True)
+                with be.telemetry.call("ticker"):
+                    be._lease_tick(bump=True)
             be.telemetry.count("ticker_rounds")
             fails = 0
         except Exception as e:   # noqa: BLE001 — a daemon thread must
@@ -395,6 +397,13 @@ class DistributedBackend:
     def _degraded(self) -> bool:
         return bool(self._dead or self._data_dead)
 
+    def _launch(self, name: str, *args, ops=None):
+        """Dispatch store program ``name`` (of ``ops``, default this
+        backend's) under a ``launch.<name>`` span.  Every program launch
+        goes through here, so the trace names each dispatch."""
+        with self.telemetry.span(f"launch.{name}"):
+            return (ops or self.ops)[name](*args)
+
     # -- lease detector ----------------------------------------------------
     def _lease_expired(self, misses: np.ndarray, last_t: np.ndarray,
                        g: int, now: float) -> bool:
@@ -415,21 +424,24 @@ class DistributedBackend:
         idle ticker age leases through it, mutating ops bump in-body."""
         if self.lease_misses <= 0:
             return
-        self.telemetry.count("lease_ticks")
-        if bump:
-            self.store = self.ops["tick"](self.store)
-        now = time.monotonic()
-        self._last_traffic_t = now
-        # one combined device->host fetch for both planes' counters (a
-        # second sequential sync would double the per-op detection tax)
-        hb, dhb = jax.device_get((self.store.hb, self.store.data.hb))
-        self._age_plane(hb, self._last_hb, self._hb_misses, self._hb_t,
-                        self._dead, self._demote, now)
-        self._age_plane(dhb, self._last_data_hb, self._data_hb_misses,
-                        self._data_hb_t, self._data_dead,
-                        self._demote_data, now)
-        self._last_hb = hb
-        self._last_data_hb = dhb
+        tel = self.telemetry
+        with tel.span("lease_tick"):
+            tel.count("lease_ticks")
+            if bump:
+                self.store = self._launch("tick", self.store)
+            now = time.monotonic()
+            self._last_traffic_t = now
+            # one combined device->host fetch for both planes' counters
+            # (a second sequential sync would double the per-op
+            # detection tax)
+            hb, dhb = tel.sync((self.store.hb, self.store.data.hb))
+            self._age_plane(hb, self._last_hb, self._hb_misses,
+                            self._hb_t, self._dead, self._demote, now)
+            self._age_plane(dhb, self._last_data_hb, self._data_hb_misses,
+                            self._data_hb_t, self._data_dead,
+                            self._demote_data, now)
+            self._last_hb = hb
+            self._last_data_hb = dhb
 
     def _age_plane(self, hb, last, misses, last_t, dead, demote,
                    now: float):
@@ -459,8 +471,6 @@ class DistributedBackend:
         if detected:
             self.detected.append(g)
         self.telemetry.count("index_demotions")
-        self.telemetry.span({"event": "demote", "plane": "index",
-                             "server": g, "detected": detected})
 
     def _demote_data(self, g: int, detected: bool = False):
         """Degraded routing for DATA server ``g``: GETs of its shard fail
@@ -474,8 +484,6 @@ class DistributedBackend:
         if detected:
             self.detected_data.append(g)
         self.telemetry.count("data_demotions")
-        self.telemetry.span({"event": "demote", "plane": "data",
-                             "server": g, "detected": detected})
 
     def lease_stalled(self) -> bool:
         """Did the last observation round see a not-yet-demoted server's
@@ -539,22 +547,24 @@ class DistributedBackend:
 
     def put(self, keys, vals, valid):
         with self._mu:
-            n = int(valid.sum())
+            n = int(self.telemetry.sync(valid.sum()))
             self._ensure_log_room(n)
             self._pending_bound += n
             # healthy cluster -> the lean variant; any masked-dead server
             # -> the variant with the old-slot replica probe (frees stay
             # exact at temporary primaries) and the off-dead-shard value
             # displacement
-            op = self.ops["put_degraded" if self._degraded() else "put"]
-            self.store, ok, addrs, nrep = op(self.store, keys, vals, valid)
+            self.store, ok, addrs, nrep = self._launch(
+                "put_degraded" if self._degraded() else "put",
+                self.store, keys, vals, valid)
             self._lease_tick()
             return ok, addrs, nrep
 
     def get(self, keys, valid):
+        tel = self.telemetry
         with self._mu:
-            addrs, found, acc, vals, routed, val_ok = self.ops["get"](
-                self.store, keys, valid)
+            addrs, found, acc, vals, routed, val_ok = self._launch(
+                "get", self.store, keys, valid)
             found = found & valid
             hops = valid.astype(I32)
             # second hop (paper: the client reads the value from the data
@@ -564,27 +574,31 @@ class DistributedBackend:
             # holder (mirror failover); a fetch-overflow lane re-enters
             # the client's retry loop as un-routed
             need = found & ~val_ok
-            if bool(need.any()):
-                self.store, fvals, fok = self.ops["fetch"](
-                    self.store, addrs, need)
+            if bool(tel.sync(need.any())):
+                self.store, fvals, fok = self._launch(
+                    "fetch", self.store, addrs, need)
                 vals = jnp.where(need[:, None], fvals, vals)
                 routed = routed & (~need | fok)
                 hops = hops + need.astype(I32)
+                # lanes the second hop answered this round (the rest
+                # retry); read only here, so a healthy GET reads nothing
+                tel.count("hops2_gets",
+                          int(tel.sync((need & routed).sum())))
             self._lease_tick(bump=True)
             return addrs, found, acc, vals, routed & valid, hops
 
     def delete(self, keys, valid):
         with self._mu:
-            n = int(valid.sum())
+            n = int(self.telemetry.sync(valid.sum()))
             self._ensure_log_room(n)
             self._pending_bound += n
             # healthy cluster -> probe-free variant (all requests land on
             # true primaries); any masked-dead server -> the degraded
             # variant that answers found at temporary primaries via the
             # replica probe
-            op = self.ops[
-                "delete_degraded" if self._degraded() else "delete"]
-            self.store, ok, found, nrep = op(self.store, keys, valid)
+            self.store, ok, found, nrep = self._launch(
+                "delete_degraded" if self._degraded() else "delete",
+                self.store, keys, valid)
             self._lease_tick()
             return ok, found & valid, nrep
 
@@ -597,13 +611,11 @@ class DistributedBackend:
             # make_ops' lru_cache) one scan op per distinct limit so a
             # caller asking for more than the construction-time default
             # is honored
-            if limit == self.scan_limit:
-                scan_op = self.ops["scan"]
-            else:
-                scan_op = kv.make_ops(self.mesh, self.cfg,
-                                      capacity_q=self.capacity_q,
-                                      scan_limit=limit)["scan"]
-            k, a, covered, self.store = scan_op(self.store, loa, hia)
+            ops = None if limit == self.scan_limit else kv.make_ops(
+                self.mesh, self.cfg, capacity_q=self.capacity_q,
+                scan_limit=limit)
+            k, a, covered, self.store = self._launch(
+                "scan", self.store, loa, hia, ops=ops)
             n = (k != key_inf(k.dtype)).sum().astype(I32)
             self._pending_bound = 0          # scan drained the logs
             self._lease_tick()
@@ -611,7 +623,8 @@ class DistributedBackend:
 
     def apply_async(self):
         with self._mu:
-            self.store = self.ops["apply"](self.store)
+            self.telemetry.count("apply_rounds")
+            self.store = self._launch("apply", self.store)
             self._pending_bound = max(
                 0, self._pending_bound - self.cfg.async_apply_batch)
             self._lease_tick()
@@ -620,15 +633,19 @@ class DistributedBackend:
         """One routed flush of the pending free queues (slots freed on a
         remote shard travel home and become allocatable)."""
         with self._mu:
-            self.store = self.ops["gc"](self.store)
+            self.telemetry.count("gc_rounds")
+            self.store = self._launch("gc", self.store)
             self._lease_tick()
 
     def pending_frees(self) -> int:
         with self._mu:
-            return int(lg.pending_count(self.store.data.freeq).sum())
+            return int(self.telemetry.sync(
+                lg.pending_count(self.store.data.freeq).sum()))
 
     def drain(self):
-        with self._mu:
+        tel = self.telemetry
+        with self._mu, tel.span("drain"):
+            tel.count("drains")
             while self.pending_ops() > 0:
                 self.apply_async()
             self._pending_bound = 0
@@ -645,8 +662,8 @@ class DistributedBackend:
 
     def pending_ops(self) -> int:
         with self._mu:
-            return int(jnp.max(self.store.blog.tail
-                               - self.store.blog.applied))
+            return int(self.telemetry.sync(
+                jnp.max(self.store.blog.tail - self.store.blog.applied)))
 
     def migrate_values(self) -> int:
         """Background value migration (host-side): move degraded-write
@@ -655,7 +672,8 @@ class DistributedBackend:
         moved."""
         with self._mu:
             self.store, moved = kv.migrate_values(
-                self.store, self.cfg, apply_fn=self.ops["apply"])
+                self.store, self.cfg,
+                apply_fn=functools.partial(self._launch, "apply"))
             return moved
 
     def _wipe_capability(self, what: str) -> bool:
@@ -723,8 +741,6 @@ class DistributedBackend:
             self._hb_misses[server] = 0
             self._hb_t[server] = time.monotonic()
             self.telemetry.count("index_recoveries")
-            self.telemetry.span({"event": "recover", "plane": "index",
-                                 "server": server, "online": online})
             return RecoverResult(server, online, n_reb, self.pending_ops())
 
     def fail_data_server(self, server: int) -> FailResult:
@@ -764,14 +780,13 @@ class DistributedBackend:
                 # the routing view even if the lease had not expired yet
                 self._demote_data(server)
             self.store = kv.recover_data_server(
-                self.store, server, self.cfg, apply_fn=self.ops["apply"])
+                self.store, server, self.cfg,
+                apply_fn=functools.partial(self._launch, "apply"))
             self._data_severed.discard(server)
             self._data_dead.discard(server)
             self._data_hb_misses[server] = 0
             self._data_hb_t[server] = time.monotonic()
             self.telemetry.count("data_recoveries")
-            self.telemetry.span({"event": "recover", "plane": "data",
-                                 "server": server})
 
     def telemetry_gauges(self) -> dict:
         """Snapshot-time gauges for ``client.metrics()``: the store's
@@ -826,79 +841,85 @@ class HiStoreClient:
 
     # -- public ops --------------------------------------------------------
     def put(self, keys, values=None) -> PutResult:
-        keys = self._as_keys(keys)
-        q = keys.shape[0]
-        if q == 0:
-            return PutResult(jnp.zeros((0,), bool), jnp.zeros((0,), I32), 0,
-                             jnp.zeros((0,), I32))
-        vals = self._as_values(values, q)
-        t0 = time.perf_counter()
-        oks, addrs, reps, retries = [], [], [], 0
-        for s in range(0, q, self.max_batch):
-            o, a, rep, r = self._put_chunk(keys[s:s + self.max_batch],
-                                           vals[s:s + self.max_batch])
-            oks.append(o)
-            addrs.append(a)
-            reps.append(rep)
-            retries = max(retries, r)
-        self.stats["puts"] += q
         tel = self.telemetry
-        if tel.enabled:
-            tel.count("put_ops", q)
-            tel.observe("put", time.perf_counter() - t0)
-        self._note_mutations(q)
-        return PutResult(jnp.concatenate(oks), jnp.concatenate(addrs),
-                         retries, jnp.concatenate(reps))
+        with tel.call("put", np.size(keys)):
+            with tel.span("prepare"):
+                keys = self._as_keys(keys)
+                q = keys.shape[0]
+                if q == 0:
+                    return PutResult(jnp.zeros((0,), bool),
+                                     jnp.zeros((0,), I32), 0,
+                                     jnp.zeros((0,), I32))
+                vals = self._as_values(values, q)
+            t0 = time.perf_counter()
+            oks, addrs, reps, retries = [], [], [], 0
+            for s in range(0, q, self.max_batch):
+                o, a, rep, r = self._put_chunk(keys[s:s + self.max_batch],
+                                               vals[s:s + self.max_batch])
+                oks.append(o)
+                addrs.append(a)
+                reps.append(rep)
+                retries = max(retries, r)
+            self.stats["puts"] += q
+            if tel.enabled:
+                tel.count("put_ops", q)
+                tel.count("put.calls")
+                tel.observe("put", time.perf_counter() - t0)
+            self._note_mutations(q)
+            return PutResult(jnp.concatenate(oks), jnp.concatenate(addrs),
+                             retries, jnp.concatenate(reps))
 
     def get(self, keys) -> GetResult:
-        keys = self._as_keys(keys)
-        q = keys.shape[0]
-        if q == 0:
-            W = getattr(self.backend, "value_words", 1)
-            return GetResult(jnp.zeros((0,), I32), jnp.zeros((0,), bool),
-                             jnp.zeros((0,), I32), jnp.zeros((0, W), I32),
-                             jnp.zeros((0,), bool), jnp.zeros((0,), I32))
-        t0 = time.perf_counter()
-        outs = [self._get_chunk(keys[s:s + self.max_batch])
-                for s in range(0, q, self.max_batch)]
-        self.stats["gets"] += q
-        res = GetResult(*[jnp.concatenate(p) for p in zip(*outs)])
         tel = self.telemetry
-        if tel.enabled:
-            tel.count("get_ops", q)
-            tel.observe("get", time.perf_counter() - t0)
-            # hops == 2: reads served by the second-hop value fetch
-            # (degraded-write strays / mirror failover) — the paper's
-            # extra RTT the migration pass exists to elide.  The hops
-            # lanes are already resolved (the retry loop synced), so
-            # this is a cheap host transfer, not an extra dispatch.
-            tel.count("hops2_gets",
-                      int((np.asarray(res.hops) == 2).sum()))
-        return res
+        with tel.call("get", np.size(keys)):
+            with tel.span("prepare"):
+                keys = self._as_keys(keys)
+            q = keys.shape[0]
+            if q == 0:
+                W = getattr(self.backend, "value_words", 1)
+                return GetResult(
+                    jnp.zeros((0,), I32), jnp.zeros((0,), bool),
+                    jnp.zeros((0,), I32), jnp.zeros((0, W), I32),
+                    jnp.zeros((0,), bool), jnp.zeros((0,), I32))
+            t0 = time.perf_counter()
+            outs = [self._get_chunk(keys[s:s + self.max_batch])
+                    for s in range(0, q, self.max_batch)]
+            self.stats["gets"] += q
+            res = GetResult(*[jnp.concatenate(p) for p in zip(*outs)])
+            if tel.enabled:
+                tel.count("get_ops", q)
+                tel.count("get.calls")
+                tel.observe("get", time.perf_counter() - t0)
+            return res
 
     def delete(self, keys) -> DeleteResult:
-        keys = self._as_keys(keys)
-        q = keys.shape[0]
-        if q == 0:
-            return DeleteResult(jnp.zeros((0,), bool),
-                                jnp.zeros((0,), bool), 0,
-                                jnp.zeros((0,), I32))
-        t0 = time.perf_counter()
-        oks, founds, reps, retries = [], [], [], 0
-        for s in range(0, q, self.max_batch):
-            o, f, rep, r = self._delete_chunk(keys[s:s + self.max_batch])
-            oks.append(o)
-            founds.append(f)
-            reps.append(rep)
-            retries = max(retries, r)
-        self.stats["deletes"] += q
         tel = self.telemetry
-        if tel.enabled:
-            tel.count("delete_ops", q)
-            tel.observe("delete", time.perf_counter() - t0)
-        self._note_mutations(q)
-        return DeleteResult(jnp.concatenate(oks), jnp.concatenate(founds),
-                            retries, jnp.concatenate(reps))
+        with tel.call("delete", np.size(keys)):
+            with tel.span("prepare"):
+                keys = self._as_keys(keys)
+            q = keys.shape[0]
+            if q == 0:
+                return DeleteResult(jnp.zeros((0,), bool),
+                                    jnp.zeros((0,), bool), 0,
+                                    jnp.zeros((0,), I32))
+            t0 = time.perf_counter()
+            oks, founds, reps, retries = [], [], [], 0
+            for s in range(0, q, self.max_batch):
+                o, f, rep, r = self._delete_chunk(
+                    keys[s:s + self.max_batch])
+                oks.append(o)
+                founds.append(f)
+                reps.append(rep)
+                retries = max(retries, r)
+            self.stats["deletes"] += q
+            if tel.enabled:
+                tel.count("delete_ops", q)
+                tel.count("delete.calls")
+                tel.observe("delete", time.perf_counter() - t0)
+            self._note_mutations(q)
+            return DeleteResult(jnp.concatenate(oks),
+                                jnp.concatenate(founds), retries,
+                                jnp.concatenate(reps))
 
     def scan(self, lo, hi, limit: Optional[int] = None) -> ScanResult:
         kd = key_dtype()
@@ -908,9 +929,17 @@ class HiStoreClient:
             kd_inf = jnp.zeros((0,), kd)
             return ScanResult(kd_inf, jnp.zeros((0,), I32),
                               jnp.zeros((), I32), True, ())
+        tel = self.telemetry
+        with tel.call("scan", limit):
+            return self._scan(lo, hi, limit, kd)
+
+    def _scan(self, lo, hi, limit: int, kd) -> ScanResult:
+        tel = self.telemetry
         t0 = time.perf_counter()
-        k, a, n, covered = self.backend.scan(
-            jnp.asarray(lo, kd), jnp.asarray(hi, kd), limit)
+        with tel.span("prepare"):
+            lo, hi = jnp.asarray(lo, kd), jnp.asarray(hi, kd)
+        k, a, n, covered = self.backend.scan(lo, hi, limit)
+        cov = tel.sync(covered)
         self.stats["scans"] += 1
         # scan-completeness retry: a group with zero live, unsevered
         # holders answered nothing.  Each rescan is an observation round
@@ -922,7 +951,7 @@ class HiStoreClient:
         budget = min(self.max_retries,
                      max(getattr(self.backend, "lease_misses", 0), 0) + 1)
         tries = 0
-        while (not bool(np.asarray(covered).all())) and tries < budget:
+        while not cov.all() and tries < budget:
             # rescans only help while the detector is still watching a
             # stalled heartbeat; once detection settles (holders already
             # demoted — or oracle-failed), coverage can only return via
@@ -930,22 +959,19 @@ class HiStoreClient:
             if not self.backend.lease_stalled():
                 break
             tries += 1
-            self.stats["retries"] += 1
-            self.telemetry.count("retries")
-            self._retry_pause(budget)
-            k, a, n, covered = self.backend.scan(
-                jnp.asarray(lo, kd), jnp.asarray(hi, kd), limit)
-        cov = np.asarray(covered)
+            with tel.span("retry"):
+                self.stats["retries"] += 1
+                tel.count("retries")
+                self._retry_pause(budget)
+            k, a, n, covered = self.backend.scan(lo, hi, limit)
+            cov = tel.sync(covered)
         missing = tuple(int(g) for g in np.nonzero(~cov)[0].tolist())
-        tel = self.telemetry
         if tel.enabled:
             tel.count("scan_ops")
+            tel.count("scan.calls")
             tel.observe("scan", time.perf_counter() - t0)
             if missing:
                 tel.count("incomplete_scans")
-            tel.span({"op": "scan", "limit": limit, "retries": tries,
-                      "seconds": time.perf_counter() - t0,
-                      "missing_groups": list(missing)})
         lim = min(limit, k.shape[0])
         return ScanResult(k[:lim], a[:lim],
                           jnp.minimum(n, lim).astype(I32),
@@ -1032,11 +1058,6 @@ class HiStoreClient:
         """The snapshot in Prometheus text exposition format."""
         return tm.render_text(self.metrics())
 
-    def dump_trace(self, path) -> None:
-        """Write the op-trace ring (``cfg.telemetry="trace"``) as JSON;
-        an empty list in the other modes."""
-        self.telemetry.dump_trace(path)
-
     # -- batching / retry internals ---------------------------------------
     def _as_keys(self, keys):
         k = jnp.asarray(keys, key_dtype())
@@ -1101,117 +1122,99 @@ class HiStoreClient:
 
     def _put_chunk(self, keys, vals):
         tel = self.telemetry
-        tr = tel.tracing
-        t0 = time.perf_counter()
         q = keys.shape[0]
-        kp, pending = self._pad(keys)
-        vp = jnp.zeros((kp.shape[0], vals.shape[1]), vals.dtype
-                       ).at[:q].set(vals)
-        ev = ([{"phase": "route", "seconds": time.perf_counter() - t0}]
-              if tr else None)
-        ok_all = jnp.zeros_like(pending)
-        addr_all = jnp.full(kp.shape, -1, I32)
-        rep_all = jnp.zeros(kp.shape, I32)
+        with tel.span("prepare"):
+            kp, pending = self._pad(keys)
+            vp = jnp.zeros((kp.shape[0], vals.shape[1]), vals.dtype
+                           ).at[:q].set(vals)
+            ok_all = jnp.zeros_like(pending)
+            addr_all = jnp.full(kp.shape, -1, I32)
+            rep_all = jnp.zeros(kp.shape, I32)
         retries = 0
         while True:
-            td = time.perf_counter()
+            tel.count("put.lanes", kp.shape[0])
             ok, addrs, nrep = self.backend.put(kp, vp, pending)
-            newly = pending & ok
-            ok_all = ok_all | newly
-            addr_all = jnp.where(newly, addrs, addr_all)
-            rep_all = jnp.where(newly, nrep, rep_all)
-            pending = pending & ~ok
-            if tr:
-                ev.append({"phase": "dispatch", "try": retries,
-                           "seconds": time.perf_counter() - td})
-            if not bool(pending.any()) or retries >= self.max_retries:
+            with tel.span("merge"):
+                newly = pending & ok
+                ok_all = ok_all | newly
+                addr_all = jnp.where(newly, addrs, addr_all)
+                rep_all = jnp.where(newly, nrep, rep_all)
+                pending = pending & ~ok
+            if not bool(tel.sync(pending.any())) \
+                    or retries >= self.max_retries:
                 break
             retries += 1
-            self.stats["retries"] += 1
-            tel.count("retries")
-            tel.count("pushbacks")   # capacity push-back on a mutation
-            self._retry_pause()
-            self._make_room()
-        if tr:
-            tel.span({"op": "put", "n": q, "retries": retries,
-                      "seconds": time.perf_counter() - t0, "events": ev})
+            with tel.span("retry"):
+                self.stats["retries"] += 1
+                tel.count("retries")
+                tel.count("pushbacks")   # capacity push-back on a mutation
+                self._retry_pause()
+                self._make_room()
         return ok_all[:q], addr_all[:q], rep_all[:q], retries
 
     def _delete_chunk(self, keys):
         tel = self.telemetry
-        tr = tel.tracing
-        t0 = time.perf_counter()
         q = keys.shape[0]
-        kp, pending = self._pad(keys)
-        ev = ([{"phase": "route", "seconds": time.perf_counter() - t0}]
-              if tr else None)
-        acked = jnp.zeros_like(pending)
-        found_all = jnp.zeros_like(pending)
-        rep_all = jnp.zeros(kp.shape, I32)
+        with tel.span("prepare"):
+            kp, pending = self._pad(keys)
+            acked = jnp.zeros_like(pending)
+            found_all = jnp.zeros_like(pending)
+            rep_all = jnp.zeros(kp.shape, I32)
         retries = 0
         while True:
-            td = time.perf_counter()
+            tel.count("delete.lanes", kp.shape[0])
             ack, found, nrep = self.backend.delete(kp, pending)
-            newly = pending & ack
-            acked = acked | newly
-            found_all = found_all | (newly & found)
-            rep_all = jnp.where(newly, nrep, rep_all)
-            pending = pending & ~ack
-            if tr:
-                ev.append({"phase": "dispatch", "try": retries,
-                           "seconds": time.perf_counter() - td})
-            if not bool(pending.any()) or retries >= self.max_retries:
+            with tel.span("merge"):
+                newly = pending & ack
+                acked = acked | newly
+                found_all = found_all | (newly & found)
+                rep_all = jnp.where(newly, nrep, rep_all)
+                pending = pending & ~ack
+            if not bool(tel.sync(pending.any())) \
+                    or retries >= self.max_retries:
                 break
             retries += 1
-            self.stats["retries"] += 1
-            tel.count("retries")
-            tel.count("pushbacks")
-            self._retry_pause()
-            self._make_room()
-        if tr:
-            tel.span({"op": "delete", "n": q, "retries": retries,
-                      "seconds": time.perf_counter() - t0, "events": ev})
+            with tel.span("retry"):
+                self.stats["retries"] += 1
+                tel.count("retries")
+                tel.count("pushbacks")
+                self._retry_pause()
+                self._make_room()
         return acked[:q], found_all[:q], rep_all[:q], retries
 
     def _get_chunk(self, keys):
         tel = self.telemetry
-        tr = tel.tracing
-        t0 = time.perf_counter()
         q = keys.shape[0]
-        kp, pending = self._pad(keys)
-        ev = ([{"phase": "route", "seconds": time.perf_counter() - t0}]
-              if tr else None)
-        addr_all = jnp.full(kp.shape, -1, I32)
-        found_all = jnp.zeros_like(pending)
-        acc_all = jnp.zeros(kp.shape, I32)
-        hops_all = jnp.zeros(kp.shape, I32)
+        with tel.span("prepare"):
+            kp, pending = self._pad(keys)
+            addr_all = jnp.full(kp.shape, -1, I32)
+            found_all = jnp.zeros_like(pending)
+            acc_all = jnp.zeros(kp.shape, I32)
+            hops_all = jnp.zeros(kp.shape, I32)
         vals_all = None
         retries = 0
         while True:
-            td = time.perf_counter()
+            tel.count("get.lanes", kp.shape[0])
             addrs, found, acc, vals, routed, hops = self.backend.get(
                 kp, pending)
-            if vals_all is None:
-                vals_all = jnp.zeros_like(vals)
-            newly = pending & routed
-            addr_all = jnp.where(newly, addrs, addr_all)
-            found_all = found_all | (newly & found)
-            acc_all = jnp.where(newly, acc, acc_all)
-            hops_all = jnp.where(newly, hops, hops_all)
-            vals_all = jnp.where(newly[:, None], vals, vals_all)
-            pending = pending & ~routed
-            if tr:
-                ev.append({"phase": "dispatch", "try": retries,
-                           "seconds": time.perf_counter() - td})
-            if not bool(pending.any()) or retries >= self.max_retries:
+            with tel.span("merge"):
+                if vals_all is None:
+                    vals_all = jnp.zeros_like(vals)
+                newly = pending & routed
+                addr_all = jnp.where(newly, addrs, addr_all)
+                found_all = found_all | (newly & found)
+                acc_all = jnp.where(newly, acc, acc_all)
+                hops_all = jnp.where(newly, hops, hops_all)
+                vals_all = jnp.where(newly[:, None], vals, vals_all)
+                pending = pending & ~routed
+            if not bool(tel.sync(pending.any())) \
+                    or retries >= self.max_retries:
                 break
             retries += 1
-            self.stats["retries"] += 1
-            tel.count("retries")
-            self._retry_pause()
-        if tr:
-            tel.span({"op": "get", "n": q, "retries": retries,
-                      "seconds": time.perf_counter() - t0, "events": ev})
+            with tel.span("retry"):
+                self.stats["retries"] += 1
+                tel.count("retries")
+                self._retry_pause()
         # lanes still pending exhausted the retry budget: reported as
         # un-routed so push-back is distinguishable from a genuine miss
         return (addr_all[:q], found_all[:q], acc_all[:q], vals_all[:q],

@@ -782,8 +782,14 @@ def _scan_body(cfg, G, limit, store: KVStore, lo, hi):
 # ---------------------------------------------------------------------------
 # Public API (jit + shard_map wrappers)
 # ---------------------------------------------------------------------------
-def _smap(mesh, f, in_specs, out_specs):
-    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+def _smap(name, mesh, f, in_specs, out_specs):
+    """``f`` shard_mapped over ``mesh`` and jitted under its own ``name``:
+    the device trace's module then reads ``jit_<name>(<fingerprint>)``."""
+    def program(*args):
+        return f(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(jax.shard_map(program, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
 
@@ -814,38 +820,46 @@ def make_ops(mesh, cfg, capacity_q: int = 64, scan_limit: int = 128):
     tick(st)                    -> st   heartbeat-only round: read-heavy
                                    clients age their leases without a
                                    mutating op in flight
+
+    Each program is jitted under the name ``histore_<key>``, so the
+    device trace's modules tell them apart.
     """
     G = mesh.devices.size
     S = _specs()
 
     put, put_degraded = (
-        _smap(mesh,
+        _smap(name, mesh,
               lambda st, k, v, m, d=d: _put_body(cfg, G, capacity_q,
                                                  st, k, v, m, d),
               (S, P(AXIS), P(AXIS), P(AXIS)),
               (S, P(AXIS), P(AXIS), P(AXIS)))
-        for d in (False, True))
-    get = _smap(mesh, lambda st, k, m: _get_body(cfg, G, capacity_q, st, k, m),
+        for name, d in (("histore_put", False),
+                        ("histore_put_degraded", True)))
+    get = _smap("histore_get", mesh,
+                lambda st, k, m: _get_body(cfg, G, capacity_q, st, k, m),
                 (S, P(AXIS), P(AXIS)),
                 (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS)))
-    fetch = _smap(mesh,
+    fetch = _smap("histore_fetch", mesh,
                   lambda st, a, m: _fetch_body(G, capacity_q, st, a, m),
                   (S, P(AXIS), P(AXIS)), (S, P(AXIS), P(AXIS)))
     delete, delete_degraded = (
-        _smap(mesh,
+        _smap(name, mesh,
               lambda st, k, m, d=d: _delete_body(cfg, G, capacity_q,
                                                  st, k, m, d),
               (S, P(AXIS), P(AXIS)),
               (S, P(AXIS), P(AXIS), P(AXIS)))
-        for d in (False, True))
-    apply_async = _smap(mesh,
+        for name, d in (("histore_delete", False),
+                        ("histore_delete_degraded", True)))
+    apply_async = _smap("histore_apply", mesh,
                         lambda st: _apply_body(cfg, cfg.async_apply_batch, st),
                         (S,), S)
-    gc = _smap(mesh, lambda st: _gc_body(G, capacity_q, st), (S,), S)
-    scan = _smap(mesh, lambda st, lo, hi: _scan_body(cfg, G, scan_limit,
-                                                     st, lo, hi),
+    gc = _smap("histore_gc", mesh, lambda st: _gc_body(G, capacity_q, st),
+               (S,), S)
+    scan = _smap("histore_scan", mesh,
+                 lambda st, lo, hi: _scan_body(cfg, G, scan_limit,
+                                               st, lo, hi),
                  (S, P(AXIS), P(AXIS)), (P(), P(), P(), S))
-    tick = _smap(mesh, _tick_body, (S,), S)
+    tick = _smap("histore_tick", mesh, _tick_body, (S,), S)
     return {"put": put, "put_degraded": put_degraded, "get": get,
             "fetch": fetch, "delete": delete,
             "delete_degraded": delete_degraded, "apply": apply_async,

@@ -1,4 +1,4 @@
-"""Ops telemetry plane: latency histograms, counters, gauges, op traces.
+"""Ops telemetry plane: latency histograms, counters, gauges, spans.
 
 The paper's core claim is a latency decomposition (indexing is up to 74%
 of op latency; §6 reports percentiles, not means), yet the repro could
@@ -11,33 +11,43 @@ stack reports through:
     fixed numpy bucket array: ``record()`` is allocation-free on the hot
     path (one integer bit-length + three scalar updates), percentiles
     (p50/p95/p99/max) are extracted at snapshot time;
-  * ``Telemetry`` — counters + per-op histograms + a bounded ring-buffer
-    op-trace recorder, keyed on ``cfg.telemetry``:
-        "off"       record/observe/span are no-ops; a snapshot taken
+  * ``Telemetry`` — counters, per-op histograms and spans, keyed on
+    ``cfg.telemetry``:
+        "off"       count/observe/span are no-ops; a snapshot taken
                     before equals one taken after any workload;
-        "counters"  counters + latency histograms (the default);
-        "trace"     counters + histograms + per-op spans
-                    (route → dispatch → retries → detection events) in a
-                    ring buffer dumpable to JSON for forensics;
+        "counters"  counters + latency histograms + spans (the default);
+  * spans — ``jax.profiler.TraceAnnotation``s named ``histore.<op>`` for
+    a client call and ``histore.<op>.<phase>`` inside it (``prepare``,
+    ``launch.<program>``, ``sync``, ``lease_tick``, ``merge``, ``drain``,
+    ``retry``).  They land in the profiler's trace on the same clock as
+    the device's events, so a device-idle gap is named by the phase the
+    host was in; with no profiler session running they record nothing;
   * ``MetricsSnapshot`` — the typed result of ``client.metrics()``, with
     ``render_text`` producing Prometheus text exposition format for
     ``client.metrics_text()``.
 
-Gauges (pending-log depth, free-queue occupancy, live servers,
-``fq_spill``) are NOT sampled on the hot path: backends surface them
-lazily at snapshot time via ``telemetry_gauges()`` (one device fetch),
-so enabling telemetry never adds a device sync to an op body.
+Counters make no device access.  Gauges (pending-log depth, free-queue
+occupancy, live servers, ``fq_spill``) are NOT sampled on the hot path:
+backends surface them lazily at snapshot time via ``telemetry_gauges()``
+(one device fetch), so enabling telemetry never adds a device sync to an
+op body.  The op path's own device->host reads go through ``sync``,
+which names and counts them (``<op>.syncs``) and adds none.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import threading
 from typing import NamedTuple, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-MODES = ("off", "counters", "trace")
+MODES = ("off", "counters")
+SPAN_PREFIX = "histore"
+_NO_SPAN = contextlib.nullcontext()     # "off" mode: one shared no-op
 
 # log2 buckets over microseconds: bucket 0 is < 1 µs, bucket i >= 1 is
 # [2^(i-1), 2^i) µs; 48 buckets reach ~1.6e8 s — any op fits
@@ -101,34 +111,6 @@ class LatencyHistogram:
             p99=self.percentile(0.99), max=self.max)
 
 
-class OpTrace:
-    """Bounded ring buffer of op spans (plain dicts): the newest
-    ``capacity`` spans survive, the oldest are overwritten — forensics
-    memory stays O(capacity) no matter how long the client runs."""
-
-    __slots__ = ("capacity", "_buf", "_next", "_n")
-
-    def __init__(self, capacity: int = 256):
-        self.capacity = max(int(capacity), 1)
-        self._buf: list = [None] * self.capacity
-        self._next = 0
-        self._n = 0
-
-    def record(self, span: dict) -> None:
-        self._buf[self._next] = span
-        self._next = (self._next + 1) % self.capacity
-        self._n = min(self._n + 1, self.capacity)
-
-    def spans(self) -> list:
-        """Oldest-to-newest list of recorded spans."""
-        if self._n < self.capacity:
-            return [s for s in self._buf[:self._n]]
-        return self._buf[self._next:] + self._buf[:self._next]
-
-    def __len__(self) -> int:
-        return self._n
-
-
 class MetricsSnapshot(NamedTuple):
     """Typed result of ``client.metrics()``: a point-in-time copy —
     mutating the live telemetry after a snapshot never changes it."""
@@ -136,14 +118,37 @@ class MetricsSnapshot(NamedTuple):
     counters: dict
     gauges: dict
     latency: dict          # op name -> LatencySnapshot
-    trace_len: int
 
     def to_dict(self) -> dict:
         return {"mode": self.mode, "counters": dict(self.counters),
                 "gauges": dict(self.gauges),
                 "latency": {k: s._asdict() for k, s in
-                            sorted(self.latency.items())},
-                "trace_len": self.trace_len}
+                            sorted(self.latency.items())}}
+
+
+class _Call:
+    """The span of one client call.  While it is open, the spans and
+    syncs this thread opens are named after its op (``histore.<op>.*``):
+    the backend learns the op from the call, not from an argument."""
+
+    __slots__ = ("_local", "_op", "_ann", "_prev")
+
+    def __init__(self, local: threading.local, op: str, ann):
+        self._local = local
+        self._op = op
+        self._ann = ann
+        self._prev = ""
+
+    def __enter__(self):
+        self._prev = getattr(self._local, "op", "")
+        self._local.op = self._op
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._local.op = self._prev
+        return False
 
 
 class Telemetry:
@@ -151,21 +156,19 @@ class Telemetry:
     "off" mode before touching any state, so the off-mode hot path is a
     single attribute load + branch and a snapshot can never drift."""
 
-    __slots__ = ("mode", "enabled", "tracing", "_lock", "_counters",
-                 "_hists", "_trace")
+    __slots__ = ("mode", "enabled", "_lock", "_counters", "_hists",
+                 "_local")
 
-    def __init__(self, mode: str = "counters",
-                 trace_capacity: int = 256):
+    def __init__(self, mode: str = "counters"):
         if mode not in MODES:
             raise ValueError(
                 f"cfg.telemetry must be one of {MODES}, got {mode!r}")
         self.mode = mode
         self.enabled = mode != "off"
-        self.tracing = mode == "trace"
         self._lock = threading.Lock()   # ticker thread vs foreground
         self._counters: dict[str, int] = {}
         self._hists: dict[str, LatencyHistogram] = {}
-        self._trace = OpTrace(trace_capacity) if self.tracing else None
+        self._local = threading.local()   # the op of this thread's call
 
     # -- hot-path mutators -------------------------------------------------
     def count(self, name: str, n: int = 1) -> None:
@@ -183,14 +186,39 @@ class Telemetry:
                 h = self._hists[op] = LatencyHistogram()
             h.record(seconds)
 
-    def span(self, span: dict) -> None:
-        """Record one op-trace span (trace mode only).  Spans are plain
-        dicts; the client records {op, n, retries, seconds, events} and
-        backends append detection events through the same ring."""
-        if not self.tracing:
-            return
-        with self._lock:
-            self._trace.record(span)
+    # -- spans -------------------------------------------------------------
+    def call(self, op: str, lanes: Optional[int] = None):
+        """Context of one client call, span ``histore.<op>``; ``lanes``
+        (the request's key count) rides along as the span's metadata."""
+        if not self.enabled:
+            return _NO_SPAN
+        name = f"{SPAN_PREFIX}.{op}"
+        ann = (TraceAnnotation(name) if lanes is None
+               else TraceAnnotation(name, lanes=int(lanes)))
+        return _Call(self._local, op, ann)
+
+    def span(self, phase: str):
+        """Context of one phase of the current call: span
+        ``histore.<op>.<phase>`` (``histore.<phase>`` outside a call)."""
+        if not self.enabled:
+            return _NO_SPAN
+        return TraceAnnotation(self._name(phase))
+
+    def sync(self, x):
+        """``jax.device_get(x)``: one blocking device->host read of the
+        op path, under a ``sync`` span and counted as ``<op>.syncs``."""
+        if not self.enabled:
+            return jax.device_get(x)
+        op = getattr(self._local, "op", "")
+        if op:
+            self.count(f"{op}.syncs")
+        with TraceAnnotation(self._name("sync")):
+            return jax.device_get(x)
+
+    def _name(self, phase: str) -> str:
+        op = getattr(self._local, "op", "")
+        return (f"{SPAN_PREFIX}.{op}.{phase}" if op
+                else f"{SPAN_PREFIX}.{phase}")
 
     # -- read side ---------------------------------------------------------
     def counter(self, name: str) -> int:
@@ -202,26 +230,20 @@ class Telemetry:
             return MetricsSnapshot(
                 mode=self.mode, counters=dict(self._counters),
                 gauges=dict(gauges or {}),
-                latency={k: h.snapshot() for k, h in self._hists.items()},
-                trace_len=len(self._trace) if self._trace else 0)
-
-    def trace_spans(self) -> list:
-        with self._lock:
-            return self._trace.spans() if self._trace else []
-
-    def dump_trace(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump(self.trace_spans(), f, indent=2, default=str)
+                latency={k: h.snapshot() for k, h in self._hists.items()})
 
 
 def render_text(snap: MetricsSnapshot) -> str:
     """Prometheus text exposition format for a snapshot: counters as
     ``histore_<name>_total``, gauges as ``histore_<name>``, latency
-    histograms as one summary family with per-op labels."""
+    histograms as one summary family with per-op labels.  A dotted
+    counter name (``get.syncs``) is exposed with underscores
+    (``histore_get_syncs_total``), as the format requires."""
     lines = [f"# histore telemetry (mode={snap.mode})"]
     for name in sorted(snap.counters):
-        lines.append(f"# TYPE histore_{name}_total counter")
-        lines.append(f"histore_{name}_total {snap.counters[name]}")
+        m = name.replace(".", "_")
+        lines.append(f"# TYPE histore_{m}_total counter")
+        lines.append(f"histore_{m}_total {snap.counters[name]}")
     for name in sorted(snap.gauges):
         lines.append(f"# TYPE histore_{name} gauge")
         lines.append(f"histore_{name} {snap.gauges[name]}")

@@ -1,4 +1,4 @@
-"""Ops telemetry plane: histograms, counters, traces, and the knob.
+"""Ops telemetry plane: histograms, counters, profiler spans, the knob.
 
 The counters are verified DIFFERENTIALLY: a seeded oracle trace is
 replayed through an instrumented client and every telemetry counter must
@@ -6,8 +6,9 @@ equal the ground truth recomputed from the trace itself (op counts,
 client-observed retries, demotions delivered only through severed
 heartbeats — ``oracle_kills == 0``).  The "off" mode is held to a hard
 contract: a snapshot taken before a workload equals one taken after.
+Spans are read back from a real ``jax.profiler`` trace of a CPU run.
 """
-import json
+import glob
 import time
 import warnings
 
@@ -65,14 +66,6 @@ def test_histogram_empty_and_submicro():
     h.record(2e-7)                      # sub-microsecond -> bucket 0
     s = h.snapshot()
     assert s.count == 1 and s.p50 == pytest.approx(2e-7)  # clipped to max
-
-
-def test_optrace_ring_is_bounded():
-    tr = tm.OpTrace(capacity=4)
-    for i in range(10):
-        tr.record({"i": i})
-    assert len(tr) == 4
-    assert [s["i"] for s in tr.spans()] == [6, 7, 8, 9]
 
 
 def test_invalid_mode_rejected_at_construction():
@@ -141,7 +134,7 @@ def test_detector_demotions_with_zero_oracle_kills():
 
 def test_off_mode_records_nothing():
     """cfg.telemetry="off": a snapshot before the workload equals one
-    after — no counters, no histograms, no trace, no gauges."""
+    after — no counters, no histograms, no gauges."""
     client = _local_client(telemetry="off")
     before = client.metrics()
     trace = gen_ops(5, "uniform", n_events=8, batch=16)
@@ -149,33 +142,8 @@ def test_off_mode_records_nothing():
     after = client.metrics()
     assert before == after
     assert after.counters == {} and after.latency == {}
-    assert after.gauges == {} and after.trace_len == 0
+    assert after.gauges == {}
     assert client.stats["puts"] > 0     # the workload itself did run
-
-
-def test_trace_mode_spans_and_dump(tmp_path):
-    client = _local_client(telemetry="trace")
-    keys = np.arange(1, 33)
-    assert client.put(keys, keys).all_ok
-    client.get(keys)
-    client.scan(1, 100, 16)
-    spans = client.telemetry.trace_spans()
-    assert {s["op"] for s in spans} >= {"put", "get", "scan"}
-    put_span = next(s for s in spans if s["op"] == "put")
-    phases = [e["phase"] for e in put_span["events"]]
-    assert phases[0] == "route" and "dispatch" in phases
-    out = tmp_path / "trace.json"
-    client.dump_trace(out)
-    assert {s["op"] for s in json.loads(out.read_text())} \
-        == {s["op"] for s in spans}
-
-
-def test_counters_mode_has_no_trace():
-    client = _local_client(telemetry="counters")
-    keys = np.arange(1, 17)
-    assert client.put(keys, keys).all_ok
-    assert client.telemetry.trace_spans() == []
-    assert client.metrics().trace_len == 0
 
 
 # ---------------------------------------------------------------------------
@@ -284,3 +252,145 @@ def test_ticker_gave_up_is_latched_and_counted():
             "a gave-up ticker must not silently restart"
     client.stop_ticker()                    # explicit stop clears the latch
     assert backend._ticker_gave_up is False
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans, sync counts and program names
+# ---------------------------------------------------------------------------
+RCFG = scaled(log_capacity=64, async_apply_batch=16, lease_misses=3,
+              lease_clock="rounds")
+
+GET_PHASES = ("prepare", "launch.get", "sync", "lease_tick",
+              "launch.tick", "merge")
+
+
+def _host_spans(trace_dir) -> list:
+    """(name, start_ns, end_ns, stats) of every ``histore.*`` event on the
+    host planes of the one trace written under ``trace_dir``."""
+    (path,) = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("histore."):
+                    stats = {k: v for k, v in e.stats}
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, stats))
+    return out
+
+
+def _delta(client, before: dict) -> dict:
+    after = client.metrics().counters
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+def test_spans_nest_inside_the_get_call(tmp_path):
+    """Under a profiler session a GET call is one ``histore.get`` span
+    carrying its lane count, with every phase of the call inside it."""
+    client = _one_dev_client(RCFG)
+    keys = np.arange(1, 17)
+    assert client.put(keys, keys).all_ok       # compile outside the trace
+    client.get(keys)
+    with jax.profiler.trace(str(tmp_path)):
+        assert client.put(keys, keys).all_ok
+        assert client.get(keys).all_found
+        client.scan(1, 100, 16)
+    spans = _host_spans(tmp_path)
+    names = {n for n, *_ in spans}
+    assert {"histore.put", "histore.get", "histore.scan",
+            "histore.put.launch.put", "histore.scan.launch.scan"} <= names
+    (call,) = [s for s in spans if s[0] == "histore.get"]
+    assert call[3] == {"lanes": 16}
+    inner = {n for n, s, e, _ in spans if n.startswith("histore.get.")
+             and call[1] <= s and e <= call[2]}
+    assert inner == {f"histore.get.{p}" for p in GET_PHASES}
+    assert not [n for n, s, e, _ in spans if n.startswith("histore.get.")
+                and not (call[1] <= s and e <= call[2])]
+
+
+def test_off_mode_opens_no_span_and_counts_nothing(tmp_path):
+    cfg = scaled(log_capacity=64, async_apply_batch=16,
+                 telemetry="off")
+    client = _one_dev_client(cfg)
+    keys = np.arange(1, 17)
+    assert client.put(keys, keys).all_ok
+    with jax.profiler.trace(str(tmp_path)):
+        assert client.get(keys).all_found
+        assert client.put(keys, keys).all_ok
+    assert _host_spans(tmp_path) == []
+    assert client.metrics().counters == {}
+    assert client.telemetry.span("merge") is client.telemetry.call("get")
+
+
+def test_trace_mode_is_rejected():
+    with pytest.raises(ValueError, match="telemetry"):
+        tm.Telemetry("trace")
+    with pytest.raises(ValueError, match="telemetry"):
+        _local_client(telemetry="trace")
+
+
+def test_healthy_get_counts_three_syncs():
+    """need.any(), the heartbeat fetch and pending.any(): nothing more
+    (the hops2 counter reads no device array on the healthy path)."""
+    client = _one_dev_client(RCFG)
+    keys = np.arange(1, 17)
+    assert client.put(keys, keys).all_ok
+    before = client.metrics().counters
+    assert client.get(keys).all_found
+    d = _delta(client, before)
+    assert d["get.syncs"] == 3 and d["get.calls"] == 1
+    assert d["get.lanes"] == 16 and d["get_ops"] == 16
+    assert d["lease_ticks"] == 1 and "hops2_gets" not in d
+
+
+def test_put_sync_count_is_pinned():
+    """valid.sum(), the heartbeat fetch and pending.any()."""
+    client = _one_dev_client(RCFG)
+    keys = np.arange(1, 17)
+    assert client.put(keys, keys).all_ok
+    before = client.metrics().counters
+    assert client.put(keys + 100, keys).all_ok
+    d = _delta(client, before)
+    assert d["put.syncs"] == 3 and d["put.calls"] == 1
+    assert d["put.lanes"] == 16
+    assert "drains" not in d and "apply_rounds" not in d
+
+
+def test_a_put_that_forces_a_drain_counts_it():
+    """A full log (64 entries) makes the next PUT drain first: one drain
+    of 64 / 16 = 4 apply rounds, each read back through pending_ops()."""
+    client = _one_dev_client(RCFG)
+    keys = np.arange(1, 65)
+    assert client.put(keys, keys).all_ok
+    before = client.metrics().counters
+    assert client.put(keys[:16] + 1000, keys[:16]).all_ok
+    d = _delta(client, before)
+    assert d["drains"] == 1 and d["apply_rounds"] == 4
+    assert "gc_rounds" not in d
+    # valid.sum(), 5 pending_ops(), 1 pending_frees(), 4 + 1 heartbeat
+    # fetches (each apply round ages the leases), pending.any()
+    assert d["put.syncs"] == 1 + 5 + 1 + 5 + 1
+
+
+def test_store_programs_have_distinct_names():
+    mesh = make_mesh((len(jax.devices()),), (kv.AXIS,))
+    ops = kv.make_ops(mesh, RCFG, capacity_q=64)
+    names = {f.__name__ for f in ops.values()}
+    assert len(names) == len(ops) == 10
+    assert names == {f"histore_{k}" for k in ops}
+    store = kv.create(mesh, 512, RCFG)
+    assert "module @jit_histore_tick" in ops["tick"].lower(store).as_text()
+
+
+def test_dotted_counters_render_as_prometheus_names():
+    client = _local_client()
+    keys = np.arange(1, 17)
+    assert client.put(keys, keys).all_ok
+    text = client.metrics_text()
+    assert "histore_put_calls_total 1" in text
+    assert "histore_put_syncs_total 2" in text   # valid.sum, pending.any
+    assert "put.calls" not in text
